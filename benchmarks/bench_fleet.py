@@ -38,7 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit
-from repro.runtime.fleet import FleetOverloadError, ServingFleet
+from repro.runtime.fleet import (FleetOverloadError, ServingFleet,
+                                 check_one_process_per_chip)
 from repro.runtime.supervisor import BackoffPolicy
 
 DEFAULT_SHAPES = ((16, 512),)
@@ -220,6 +221,7 @@ def _overload_leg(K: int, N: int, rng) -> None:
 
 
 def run(repeats: int = 3, shapes=DEFAULT_SHAPES) -> None:
+    check_one_process_per_chip()
     rng = np.random.default_rng(31)
     for K, N in shapes:
         _availability_and_restart_legs(int(K), int(N), rng)

@@ -35,9 +35,9 @@
 # is the row's ``speedup`` over its same-run unfused baseline when
 # present (machine-portable), else ``us_per_call``.
 #
-# All numbers are CPU (interpret-mode Pallas / XLA-CPU) wall clock — the
-# TPU-target roofline lives in EXPERIMENTS.md §Roofline, produced by
-# ``python -m repro.launch.dryrun``.
+# All numbers are CPU (interpret-mode Pallas / XLA-CPU) wall clock: they
+# check counts (launches, compiles), never chip speed.  ``chip_smoke.py``
+# at the repo root is what runs the serving path on a TPU.
 import argparse
 import json
 import sys
@@ -147,6 +147,9 @@ def main() -> None:
                          "and print the observed launch-profile roofline "
                          "table (benchmarks.roofline_report --observed)")
     args = ap.parse_args()
+    from repro.core.platform import configure_compile_cache
+
+    configure_compile_cache()
 
     if args.roofline:
         roofline_observed()

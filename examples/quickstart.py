@@ -11,6 +11,12 @@ import jax.numpy as jnp
 # re-import this module — without the guard every worker would re-run
 # the whole quickstart (including the autotuner) before serving.
 if __name__ == "__main__":
+    # the fleet demo (section 6) needs its workers off any chip this
+    # process holds: fail now, not after sections 1-5
+    from repro.runtime.fleet import check_one_process_per_chip
+
+    check_one_process_per_chip()
+
     # 1. GPUArray-style device arrays with lazy RTCG fusion (paper Fig. 3b)
     import repro.core.array as ga
 

@@ -149,14 +149,14 @@ def block_rows_candidates(n: int, lanes: int = 128) -> list[dict]:
 def batch_block_candidates(b: int) -> list[dict]:
     """``block_rows`` candidate pool for *row-segmented* kernels, where
     the blocked dimension is the batch-row count of a ``(B, N)`` operand:
-    powers of two from a single row up to one grid step over the padded
-    batch bucket (small batches — the serving sampler's B=1 softmax —
-    need tiny blocks that the flat pool never offers)."""
-    cap = 1 << (max(1, b) - 1).bit_length()  # next_pow2(b)
-    cands = [{"block_rows": r}
-             for r in (1, 2, 4, 8, 16, 32, 64, 128, 256)
-             if r <= cap]
-    return cands or [{"block_rows": 1}]
+    the blocks a TPU accepts (`dispatch.batch_block`) — multiples of 8
+    rows up to one grid step over the padded batch bucket, or, below 8
+    rows, the whole padded batch as one block."""
+    from repro.core.dispatch import batch_block
+
+    return [{"block_rows": r}
+            for r in sorted({batch_block(b, r)
+                             for r in (8, 16, 32, 64, 128, 256)})]
 
 
 def block_n_candidates(n: int) -> list[dict]:
@@ -258,11 +258,8 @@ def measure_wallclock(fn: Callable, args: Sequence[Any], *, repeats: int = 5,
 # ----------------------------------------------------------------------
 # Analytic TPU cost model: scores a blocked kernel config without running
 # it.  Inputs are abstract: bytes moved per block, flops per block, grid
-# size, vmem footprint.  Constants are TPU v5e.
+# size, vmem footprint.  Peaks come from `platform.device_peaks`.
 # ----------------------------------------------------------------------
-PEAK_FLOPS_BF16 = 197e12
-HBM_BW = 819e9
-VMEM_BYTES = 128 * 1024 * 1024  # ~128 MiB usable VMEM per core (v5e: 128MB)
 GRID_OVERHEAD_S = 1e-6  # per-grid-step dispatch overhead estimate
 MXU_DIM = 128
 SUBLANE = 8
@@ -278,10 +275,13 @@ class BlockCost:
     tile_dims: tuple = ()
 
     def seconds(self) -> float:
-        if self.vmem_bytes > VMEM_BYTES:
+        from repro.core.platform import device_peaks
+
+        peaks = device_peaks()
+        if self.vmem_bytes > peaks["vmem_bytes"]:
             return math.inf  # config does not fit VMEM: reject
-        compute_t = self.flops / PEAK_FLOPS_BF16
-        mem_t = self.hbm_bytes / HBM_BW
+        compute_t = self.flops / peaks["bf16_flops"]
+        mem_t = self.hbm_bytes / peaks["hbm_bytes_per_s"]
         align = 1.0
         for d in self.tile_dims:
             if d % MXU_DIM:  # pay for padding to the systolic array

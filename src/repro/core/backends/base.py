@@ -62,7 +62,9 @@ class ElementwiseSpec:
     out_dtypes: tuple
     needs_i: bool
     preamble: str = ""
-    interpret: bool = True     # pallas-only hint; other backends ignore
+    # pallas-only (other backends ignore it); no default: a spec must
+    # carry the flag its family resolved (`platform.interpret_mode`)
+    interpret: bool = field(kw_only=True)
 
     def token(self) -> list:
         """JSON-able identity for content-addressed caching."""
@@ -96,7 +98,7 @@ class ReductionSpec:
     multi: bool
     axis: Any = None           # None | -1 | 0
     preamble: str = ""
-    interpret: bool = True
+    interpret: bool = field(kw_only=True)
 
     def token(self) -> list:
         # repr(axis) keeps None/-1/0 distinct (`axis or 0` collapsed
@@ -119,7 +121,7 @@ class ScanSpec:
     cumop: str                 # e.g. "jnp.cumsum"
     binop: str                 # "+", "*", "jnp.maximum", "jnp.minimum"
     exclusive: bool
-    interpret: bool = True
+    interpret: bool = field(kw_only=True)
 
     def token(self) -> list:
         return ["scan", self.name, self.dtype, self.neutral, self.cumop,

@@ -148,6 +148,15 @@ def {{ name }}_fn(x):
 )
 
 
+def _cumsum_lanes(v):
+    return jnp.cumsum(v, axis=-1)
+
+
+# Names generated XLA source links against beyond the default namespace
+# (the snippet function ``cumsumf`` renders as ``cumsum_lanes``).
+_KERNEL_LIB = {"cumsum_lanes": _cumsum_lanes}
+
+
 def _with_preamble(preamble: str, src: str) -> str:
     return (preamble + "\n" + src) if preamble else src
 
@@ -214,7 +223,8 @@ class XlaBackend(Backend):
     def _compile(self, src: str, fn_name: str, name: str) -> Callable:
         from repro.core.rtcg import SourceModule
 
-        return jax.jit(SourceModule.load(src, name=name).get_function(fn_name))
+        return jax.jit(SourceModule.load(src, namespace=_KERNEL_LIB,
+                                         name=name).get_function(fn_name))
 
     @staticmethod
     def _arg_meta(kir):

@@ -56,6 +56,7 @@ from typing import Any, Callable, Sequence
 
 from repro.core.cache import LRUCache
 from repro.core.platform import LANES  # re-export: the bucketing lane width
+from repro.core.platform import SUBLANES
 
 _DEFAULT_CACHE_SIZE = int(os.environ.get("REPRO_DRIVER_CACHE_SIZE", "256"))
 
@@ -264,13 +265,29 @@ def n_bucket(n: int, lanes: int = LANES) -> int:
 
 
 # ------------------------------------------- 2-D (row-segmented) buckets
+def batch_block(b: int, block_rows: int) -> int:
+    """The row-segmented block a TPU accepts for a ``b``-row batch:
+    ``block_rows`` rounded up to a multiple of `SUBLANES`, capped at the
+    whole pow2-padded batch (a block's row count must be a multiple of
+    8 or equal the padded array's).  Every target uses the same rule,
+    so interpret-mode runs exercise the layout the chip compiles."""
+    whole = next_pow2(max(1, int(b)))
+    return min(whole, -(-int(block_rows) // SUBLANES) * SUBLANES)
+
+
 def bucket_batch(b: int, block_rows: int) -> int:
     """Padded batch-row count for a row-segmented kernel over ``(B, N)``
     operands: next multiple of ``block_rows`` (the grid must divide),
     then the next power of two — the same shape-churn bound as
     `bucket_rows`, applied to the *batch* dimension, so a batch-size
     sweep over a ``k×`` range compiles ≤ ``ceil(log2(k)) + 1`` drivers.
+    ``block_rows`` must already satisfy `batch_block`'s rule.
     """
+    if block_rows != batch_block(b, block_rows):
+        raise ValueError(
+            f"block_rows={block_rows} for a {b}-row batch is neither a "
+            f"multiple of {SUBLANES} nor the whole padded batch "
+            f"(use dispatch.batch_block)")
     rows = -(-max(1, int(b)) // block_rows) * block_rows
     bucket = next_pow2(rows)
     return -(-bucket // block_rows) * block_rows
@@ -309,14 +326,15 @@ def rc_bucket(b: int, n: int, lanes: int = LANES,
     return pair
 
 
-def default_batch_block(b: int, target_grid: int = 8, min_rows: int = 1,
+def default_batch_block(b: int, target_grid: int = 8,
                         max_rows: int = 256) -> int:
     """Bucket-derived default batch ``block_rows`` for row-segmented
-    kernels: keep the sequential grid near ``target_grid`` steps.
-    ``min_rows=1`` (not 8) because a single-row batch — the serving
-    sampler's softmax — must not pay an 8× row-padding tax."""
+    kernels: keep the sequential grid near ``target_grid`` steps.  A
+    batch under 8 rows is one whole-batch block (the B=1 serving
+    sampler pays no row-padding tax); larger batches use multiples of
+    8 rows (`batch_block`)."""
     br = next_pow2(max(1, int(b))) // target_grid
-    return max(min_rows, min(max_rows, br or min_rows))
+    return batch_block(b, min(max_rows, max(br, 1)))
 
 
 def default_block_rows(n: int, lanes: int = LANES, target_grid: int = 8,

@@ -52,7 +52,7 @@ from repro.core.backends.pallas import row_block_specs  # compat re-export
 from repro.core.cache import stable_hash
 from repro.core.platform import (DEFAULT_BLOCK_ROWS, LANES, BroadcastArg,
                                  ScalarArg, VectorArg, arg_kind,
-                                 canonical_dtype, on_tpu, pad_row_operand,
+                                 canonical_dtype, interpret_mode, pad_row_operand,
                                  parse_arguments, rows_geometry)
 
 # Compat aliases — these helpers lived here before the backend layer
@@ -75,7 +75,7 @@ class ElementwiseKernel:
         self.name = re.sub(r"\W", "_", name)
         self.preamble = preamble
         self.block_rows = block_rows
-        self.interpret = (not on_tpu()) if interpret is None else interpret
+        self.interpret = interpret_mode() if interpret is None else interpret
         self.layout = layout
         self.backend = backend  # None: resolve REPRO_BACKEND per call
 
@@ -184,10 +184,11 @@ class ElementwiseKernel:
         ragged = row_lens is not None
         b, n = self._rows_geometry(call_args)
         bucket = dispatch.rc_bucket(b, n, ragged=ragged)
-        br = (block_rows or self._tuned.get((be.name, bucket))
-              or autotune.sequence_param(f"eltwise.{self.name}", be.name,
-                                         bucket, "block_rows")
-              or self.block_rows or dispatch.default_batch_block(b))
+        br = dispatch.batch_block(b, (
+            block_rows or self._tuned.get((be.name, bucket))
+            or autotune.sequence_param(f"eltwise.{self.name}", be.name,
+                                       bucket, "block_rows")
+            or self.block_rows or dispatch.default_batch_block(b)))
         brows = dispatch.bucket_batch(b, br)
         ncols = dispatch.bucket_cols(n)
         key = ("eltwise_rows", be.name, self._content_key, brows, ncols,
@@ -250,6 +251,7 @@ class ElementwiseKernel:
         vec_bytes = sum(jnp.dtype(v.jnp_dtype).itemsize for v in self.vector_args)
         if self.layout == "rows":
             b, n = self._rows_geometry(args)
+            br = dispatch.batch_block(b, br)
             brows = dispatch.bucket_batch(b, br)
             ncols = dispatch.bucket_cols(n)
             return BlockCost(

@@ -42,8 +42,11 @@ from typing import Any
 
 #: Bumped whenever lowering or rendering semantics change: it feeds
 #: ``cache.environment_fingerprint()``, so disk-cached drivers and
-#: tuning winners from an older pipeline invalidate cleanly.
-IR_SCHEMA_VERSION = 1
+#: tuning winners from an older pipeline invalidate cleanly.  Version 2:
+#: row blocks follow the TPU tile rule (no 1/2/4-row winners survive),
+#: vector-stored flat accumulators, roll-based in-kernel scans, and a
+#: ``cumsumf`` row output prefix-summed after the Pallas kernel.
+IR_SCHEMA_VERSION = 2
 
 AXIS_TAGS = ("parallel", "sequential", "reduction")
 

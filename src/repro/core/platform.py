@@ -14,7 +14,9 @@ family or a backend.
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Any
 
 import jax
@@ -24,11 +26,66 @@ import numpy as np
 from repro.core import snippets
 
 LANES = 128  # VPU lane count — the innermost slicing axis on TPU.
-DEFAULT_BLOCK_ROWS = 8  # sublane count of a float32 VREG tile.
+SUBLANES = 8  # sublane count of a float32 VREG tile.
+DEFAULT_BLOCK_ROWS = SUBLANES
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: exactly when the
+    default backend is not a TPU.  Every Pallas call site resolves its
+    ``interpret`` flag here, so a chip run never interprets."""
+    return not on_tpu()
+
+
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+#: 16 GB HBM at 819 GB/s; 128 MiB of VMEM per TensorCore).
+PEAKS: dict = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9, "vmem_bytes": 128 << 20},
+}
+#: The chip the analytic models score for when no TPU is attached
+#: (interpret mode and compile rehearsals target it).
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(device_kind: "str | None" = None) -> dict:
+    """Peaks of ``device_kind`` — by default the first JAX device's, or
+    `TARGET_DEVICE_KIND` when that device is not a TPU.  A TPU missing
+    from `PEAKS` raises: scoring it with another chip's numbers would
+    rank configurations for the wrong hardware."""
+    if device_kind is None:
+        dev = jax.devices()[0]
+        device_kind = (dev.device_kind if dev.platform == "tpu"
+                       else TARGET_DEVICE_KIND)
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; add its "
+                       "published numbers to platform.PEAKS") from None
+
+
+#: Root of the checkout this package runs from (``src/repro/core/..``).
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; entry points call this
+    once at start-up, library modules never.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it and nothing
+    else is set; otherwise the cache is the fixed ``.jax_cache/`` at the
+    repo root (a fixed path: the directory is part of the cache key, so
+    one that moves never hits).  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def canonical_dtype(dtype):

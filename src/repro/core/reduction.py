@@ -73,7 +73,7 @@ from repro.core import backends, dispatch, snippets
 from repro.core.backends.base import ReductionSpec
 from repro.core.cache import stable_hash
 from repro.core.platform import (LANES, BroadcastArg, ScalarArg, VectorArg,
-                                 arg_kind, canonical_dtype, on_tpu,
+                                 arg_kind, canonical_dtype, interpret_mode,
                                  parse_arguments, rows_geometry)
 
 # Recognized whole-block reducers (fast path); anything else raises.
@@ -120,7 +120,7 @@ class ReductionKernel:
         self.name = re.sub(r"\W", "_", name)
         self.preamble = preamble
         self.block_rows = block_rows
-        self.interpret = (not on_tpu()) if interpret is None else interpret
+        self.interpret = interpret_mode() if interpret is None else interpret
         self.backend = backend  # None: resolve REPRO_BACKEND per call
         if axis not in (None, -1, 0):
             raise NotImplementedError("only axis=None (full), axis=-1 "
@@ -226,10 +226,11 @@ class ReductionKernel:
         tb, tn = self._domain_geometry(call_args)
         bucket = dispatch.rc_bucket(tb, tn, transposed=(self.axis == 0),
                                     ragged=ragged)
-        br = (block_rows or self._tuned.get((be.name, bucket))
-              or autotune.sequence_param(f"reduce.{self.name}", be.name,
-                                         bucket, "block_rows")
-              or self.block_rows or dispatch.default_batch_block(tb))
+        br = dispatch.batch_block(tb, (
+            block_rows or self._tuned.get((be.name, bucket))
+            or autotune.sequence_param(f"reduce.{self.name}", be.name,
+                                       bucket, "block_rows")
+            or self.block_rows or dispatch.default_batch_block(tb)))
         brows = dispatch.bucket_batch(tb, br)
         ncols = dispatch.bucket_cols(tn)
         key = ("reduce_rows", be.name, self._content_key, brows, ncols,
@@ -288,6 +289,7 @@ class ReductionKernel:
         vec_bytes = sum(jnp.dtype(v.jnp_dtype).itemsize for v in self.vector_args)
         if self.axis is not None:
             b, n = self._domain_geometry(args)
+            br = dispatch.batch_block(b, br)
             brows = dispatch.bucket_batch(b, br)
             ncols = dispatch.bucket_cols(n)
             return BlockCost(

@@ -50,12 +50,9 @@ def _default_namespace() -> dict[str, Any]:
         "math": _math,
         "partial": _functools.partial,
     }
-    try:  # TPU-specific pallas helpers; absent on some builds
-        from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas import tpu as pltpu
 
-        ns["pltpu"] = pltpu
-    except ImportError:  # pragma: no cover
-        pass
+    ns["pltpu"] = pltpu
     return ns
 
 

@@ -34,7 +34,7 @@ import numpy as np
 from repro.core import backends, dispatch
 from repro.core.backends.base import ScanSpec
 from repro.core.cache import stable_hash
-from repro.core.platform import canonical_dtype, on_tpu
+from repro.core.platform import canonical_dtype, interpret_mode
 
 _SCAN_OPS = {
     "a+b": ("jnp.cumsum", "+", "0"),
@@ -68,7 +68,7 @@ class ScanKernel:
         self.name = re.sub(r"\W", "_", name)
         self.exclusive = exclusive
         self.block_n = block_n
-        self.interpret = (not on_tpu()) if interpret is None else interpret
+        self.interpret = interpret_mode() if interpret is None else interpret
         self.backend = backend  # None: resolve REPRO_BACKEND per call
         self.spec = ScanSpec(
             name=self.name,

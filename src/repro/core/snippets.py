@@ -35,8 +35,11 @@ C_FUNC_MAP = {
     "floorf": "jnp.floor", "ceilf": "jnp.ceil",
     "erff": "jax.lax.erf", "sigmoid": "jax.nn.sigmoid",
     # row-wise inclusive prefix sum (last-axis): the sampler's
-    # inverse-CDF epilogue fuses into the ragged flush through this
-    "cumsumf": "(lambda _v: jnp.cumsum(_v, axis=-1))",
+    # inverse-CDF epilogue fuses into the ragged flush through this.
+    # The XLA backend links `cumsum_lanes` to jnp.cumsum; Pallas TPU has
+    # no cumsum lowering, so its driver scans such an output after the
+    # kernel (`backends.pallas.split_row_scans`).
+    "cumsumf": "cumsum_lanes",
 }
 
 _DECL_RE = re.compile(r"^\s*(?:const\s+)?(?:float|double|int|long|unsigned\s+int|bool)\s+(\w+)\s*=")

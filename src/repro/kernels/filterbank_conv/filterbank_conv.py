@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.platform import interpret_mode
 from repro.core.templates import KernelTemplate
 
 FILTERBANK_TMPL = KernelTemplate(
@@ -69,7 +70,7 @@ def pallas_filterbank_conv(x, filters, *, block_h: int = 8, unroll_w: bool = Tru
     """x: (H, W, C) input; filters: (F, fh, fw, C). 'valid' convolution
     (cross-correlation, as in the paper's workload) -> (H-fh+1, W-fw+1, F)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     H, W, C = x.shape
     F, fh, fw, C2 = filters.shape
     assert C == C2

@@ -26,12 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from repro.core.platform import interpret_mode
 from repro.core.templates import KernelTemplate
 
 NEG_INF = -1e30
@@ -107,7 +104,7 @@ def pallas_flash_attention(q, k, v, *, causal: bool = True,
                            interpret: bool | None = None):
     """q: (B, H, Sq, D); k, v: (B, Hk, Skv, D) with H % Hk == 0 (GQA)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     B, H, Sq, D = q.shape
     _, Hk, Skv, _ = k.shape
     assert H % Hk == 0, (H, Hk)
@@ -141,10 +138,10 @@ def pallas_flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
-        ] if pltpu else [],
+        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ) if (pltpu and not interpret) else None,
+        ) if not interpret else None,
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(B, H, pq, D)[:, :, :Sq, :]

@@ -18,12 +18,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from repro.core.platform import interpret_mode
 from repro.core.templates import KernelTemplate
 
 MATMUL_TMPL = KernelTemplate(
@@ -81,7 +78,7 @@ def pallas_matmul(x, y, bias_arr=None, *, block_m: int = 128, block_n: int = 128
     slices the result back.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     M, K = x.shape
     K2, N = y.shape
     assert K == K2, (x.shape, y.shape)
@@ -104,7 +101,7 @@ def pallas_matmul(x, y, bias_arr=None, *, block_m: int = 128, block_n: int = 128
         in_specs.append(pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)))
         inputs.append(bp)
 
-    scratch = [pltpu.VMEM((block_m, block_n), jnp.float32)] if pltpu else []
+    scratch = [pltpu.VMEM((block_m, block_n), jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid=(pm // block_m, pn // block_n, pk // block_k),
@@ -114,7 +111,7 @@ def pallas_matmul(x, y, bias_arr=None, *, block_m: int = 128, block_n: int = 128
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ) if (pltpu and not interpret) else None,
+        ) if not interpret else None,
         interpret=interpret,
     )(*inputs)
     return out[:M, :N]

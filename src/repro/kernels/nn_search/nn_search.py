@@ -18,12 +18,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from repro.core.platform import interpret_mode
 from repro.core.templates import KernelTemplate
 
 NN_TMPL = KernelTemplate(
@@ -75,7 +72,7 @@ def pallas_nn_search(targets, neighbors, *, block_t: int = 128, block_n: int = 5
                      interpret: bool | None = None):
     """targets: (T, D); neighbors: (N, D) -> (min_dist2 (T,), argmin (T,))."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     T, D = targets.shape
     N, D2 = neighbors.shape
     assert D == D2
@@ -103,10 +100,10 @@ def pallas_nn_search(targets, neighbors, *, block_t: int = 128, block_n: int = 5
         scratch_shapes=[
             pltpu.VMEM((block_t, lanes), jnp.float32),
             pltpu.VMEM((block_t, lanes), jnp.int32),
-        ] if pltpu else [],
+        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-        ) if (pltpu and not interpret) else None,
+        ) if not interpret else None,
         interpret=interpret,
     )(tp, np_)
     return od[:T, 0], oi[:T, 0]

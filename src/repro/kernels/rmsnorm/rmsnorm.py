@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.platform import interpret_mode
 from repro.core.templates import KernelTemplate
 
 RMSNORM_TMPL = KernelTemplate(
@@ -39,7 +40,7 @@ def pallas_rmsnorm(x, w, residual=None, *, eps: float = 1e-6,
                    block_rows: int = 128, interpret: bool | None = None):
     """x: (..., D) row-normalized; w: (D,). Optional fused residual add."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     orig_shape = x.shape
     D = orig_shape[-1]
     R = int(x.size // D)

@@ -21,12 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from repro.core.platform import interpret_mode
 from repro.core.templates import KernelTemplate
 
 WKV_TMPL = KernelTemplate(
@@ -63,7 +60,7 @@ def pallas_wkv6(r, k, v, w, u, *, chunk: int = 16, interpret: bool | None = None
     """r/k/v: (B, T, H, dh); w: (B, T, H, dh) decay in (0,1), f32;
     u: (H, dh) bonus, f32.  -> y (B, T, H, dh) f32."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     B, T, H, dh = r.shape
     pt = -(-T // chunk) * chunk
 
@@ -84,10 +81,10 @@ def pallas_wkv6(r, k, v, w, u, *, chunk: int = 16, interpret: bool | None = None
                   pl.BlockSpec((1, dh), lambda g, c, H=H: (g % H, 0))],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((B * H, pt, dh), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)] if pltpu else [],
+        scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-        ) if (pltpu and not interpret) else None,
+        ) if not interpret else None,
         interpret=interpret,
     )(rf, kf, vf, wf, u.astype(jnp.float32))
     return jnp.moveaxis(out[:, :T].reshape(B, H, T, dh), 1, 2)

@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import LM_SHAPES, applicable_shapes
 from repro.configs.registry import all_archs, get_config
+from repro.core.platform import TARGET_DEVICE_KIND, device_peaks
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer
@@ -35,9 +36,10 @@ from repro.sharding.partition import MeshContext, cache_spec_for, spec_for
 from repro.training.step import (abstract_opt_state, batch_specs, input_specs,
                                  make_train_step, opt_state_specs)
 
-# TPU v5e per-chip constants for the roofline terms
-PEAK_FLOPS = 197e12      # bf16 FLOP/s
-HBM_BW = 819e9           # bytes/s
+# the modelled pod's chips (the host devices stand in for them)
+_PEAKS = device_peaks(TARGET_DEVICE_KIND)
+PEAK_FLOPS = _PEAKS["bf16_flops"]
+HBM_BW = _PEAKS["hbm_bytes_per_s"]
 ICI_BW = 50e9            # bytes/s per link (~3 links usable per axis hop)
 
 

@@ -8,15 +8,7 @@ must set XLA_FLAGS before any jax initialization.
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.x; Auto is the default either way
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:
-    def _axis_kwargs(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,9 +18,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: tuple, axes: tuple):
     """Arbitrary mesh (tests use small host-device meshes, e.g. (4, 2))."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_kwargs(len(axes)))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -46,10 +46,16 @@ import jax
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.core.platform import REPO_ROOT, configure_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.schema import init_params
 from repro.serving.engine import Engine, RequestQueue
 from repro.sharding.partition import MeshContext
+
+
+# The fleet demo's warm-start store: a fixed path, so a second run warms
+# up from the first (git-ignored).
+FLEET_STORE = REPO_ROOT / ".fleet_store"
 
 
 def coalesce_demo(runtime, k: int, n: int) -> None:
@@ -81,8 +87,6 @@ def coalesce_demo(runtime, k: int, n: int) -> None:
 def fleet_demo(n_workers: int, k: int, n: int, kill: bool = False) -> None:
     """K softmax requests over an N-worker process fleet; optionally one
     injected worker death mid-traffic (availability must stay 1.0)."""
-    import tempfile
-
     from repro.runtime import ServingFleet
     from repro.runtime.supervisor import BackoffPolicy
 
@@ -99,7 +103,7 @@ def fleet_demo(n_workers: int, k: int, n: int, kill: bool = False) -> None:
     with ServingFleet(workers=n_workers, backend="xla", max_batch=8,
                       max_redispatch=5,
                       backoff=BackoffPolicy(base=0.01, cap=0.2),
-                      cache_dir=tempfile.mkdtemp(prefix="serve-fleet-"),
+                      cache_dir=str(FLEET_STORE),
                       **chaos) as fleet:
         fleet.wait_ready(timeout=300)
         t0 = time.time()
@@ -146,6 +150,11 @@ def main(argv=None):
                     help="export the flight recorder as Chrome trace "
                          "JSON at exit (arm REPRO_TRACE=spans)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
+    if args.fleet:
+        from repro.runtime.fleet import check_one_process_per_chip
+
+        check_one_process_per_chip()
 
     stats_server = None
     if args.stats_port is not None:

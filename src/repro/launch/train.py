@@ -20,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import manager as ckpt
 from repro.configs.registry import get_config
+from repro.core.platform import configure_compile_cache
 from repro.data.pipeline import SyntheticLM
 from repro.launch.mesh import make_mesh
 from repro.models.schema import count_params, init_params, param_specs
@@ -46,6 +47,7 @@ def main(argv=None):
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--override", default="", help="k=v,... ModelConfig overrides")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     for kv in filter(None, args.override.split(",")):

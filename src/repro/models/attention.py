@@ -28,7 +28,6 @@ from jax import lax
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import fused_softmax
-from repro.sharding.partition import shard_map
 
 NEG_INF = -1e30
 
@@ -203,7 +202,7 @@ def kv_sharded_decode_attention(cfg: ModelConfig, ctx, q, k_cache, v_cache,
         o = lax.psum(o_loc, "model") / jnp.maximum(l, 1e-30)[..., None]
         return o.reshape(b, 1, H, dh).astype(q_l.dtype), k_l, v_l
 
-    out, k_cache, v_cache = shard_map(
+    out, k_cache, v_cache = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(qspec, cspec, cspec, qspec, qspec, P()),
         out_specs=(qspec, cspec, cspec),

@@ -12,33 +12,10 @@ from jax import lax
 from repro.configs.base import ModelConfig
 
 
-def _resolve_tracer_type() -> type:
-    """Version-compat ``Tracer`` lookup: ``jax.core.Tracer`` has moved
-    between releases (``jax.core`` re-exports shrink over time; newer
-    trees keep it under ``jax._src.core``, some expose
-    ``jax.extend.core``).  Resolved once at import — the concrete-vs-
-    traced test sits on decode hot paths."""
-    core = getattr(jax, "core", None)
-    t = getattr(core, "Tracer", None) if core is not None else None
-    if isinstance(t, type):
-        return t
-    try:  # pragma: no cover - exercised only on jax trees without jax.core.Tracer
-        from jax.extend import core as _xcore
-        if isinstance(getattr(_xcore, "Tracer", None), type):
-            return _xcore.Tracer
-    except ImportError:
-        pass
-    from jax._src import core as _score  # pragma: no cover
-    return _score.Tracer  # pragma: no cover
-
-
-_TRACER_TYPE = _resolve_tracer_type()
-
-
 def is_tracer(x) -> bool:
     """True when ``x`` is an abstract value inside a jax trace (so RTCG
     host paths must fall back to jax ops)."""
-    return isinstance(x, _TRACER_TYPE)
+    return isinstance(x, jax.core.Tracer)
 
 
 def norm(cfg: ModelConfig, p: dict, name: str, x, *, use_pallas: bool = False,

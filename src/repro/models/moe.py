@@ -25,7 +25,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.sharding.partition import MeshContext, shard_map
+from repro.sharding.partition import MeshContext
 
 
 def _route(cfg: ModelConfig, router_w, x_flat):
@@ -138,7 +138,7 @@ def moe_ffn(cfg: ModelConfig, p: dict, x, ctx: MeshContext):
         body = functools.partial(_local_moe, cfg, capacity, n_local,
                                  ctx.model_axis, fsdp,
                                  all_axes=tuple(ctx.mesh.axis_names))
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(bspec, P(None, None), wspec13, wspec13, wspec2),
             out_specs=(bspec, P()),

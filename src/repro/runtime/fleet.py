@@ -273,6 +273,29 @@ class _WorkerSlot:
         self.ctl_pending: dict = {}  # cid -> RuntimeFuture
 
 
+def check_one_process_per_chip(env: "dict | None" = None) -> None:
+    """Fail fast where fleet workers would need a TPU chip.
+
+    A chip belongs to one process at a time.  Once this process has
+    opened JAX on a TPU it holds the chip, and every spawned worker that
+    imports JAX for the same platform would fail or hang at start-up.
+    Workers pinned off the TPU (``JAX_PLATFORMS`` without ``tpu`` in
+    ``env`` or the inherited environment) are fine.  Pinning one worker
+    per chip is not implemented."""
+    platforms = (env or {}).get("JAX_PLATFORMS",
+                                os.environ.get("JAX_PLATFORMS", ""))
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    from repro.core.platform import on_tpu
+
+    if on_tpu():
+        raise RuntimeError(
+            "ServingFleet needs one process per TPU chip: this process "
+            "holds the chip, so worker processes could not open it (they "
+            "would fail or hang at start-up).  Serve in-process through "
+            "ServingRuntime, or run the workers with JAX_PLATFORMS=cpu.")
+
+
 class ServingFleet:
     """N supervised `ServingRuntime` worker processes behind one bounded
     admission queue.  See the module docstring for the architecture;
@@ -312,6 +335,7 @@ class ServingFleet:
                  start: bool = True):
         if workers < 1:
             raise ValueError("workers must be >= 1")
+        check_one_process_per_chip(env)
         self.backend = backend
         self.window = float(window)
         self.max_batch = int(max_batch)

@@ -183,7 +183,7 @@ class ContinuousEngine:
     micro-batch: each step's live logits rows submit as one
     ``softmax.cdf`` flush — 2 generated-kernel launches per step for
     the whole batch, with the inverse-CDF cumsum fused into the flush's
-    epilogue (the per-request post-step is a single host
+    epilogue launch (the per-request post-step is a single host
     ``searchsorted``).
 
     Attention-mixer architectures only: non-attention mixers (rwkv6 /

@@ -29,18 +29,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat `shard_map`: new jax exposes ``jax.shard_map`` with
-    ``check_vma``; older releases have ``jax.experimental.shard_map``
-    with the same check under the ``check_rep`` name."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-
 LOGICAL_RULES: dict[str, tuple[str, ...]] = {
     "batch": ("pod", "data"),
     "embed": ("data",),

@@ -236,7 +236,16 @@ def test_bucket_pair_helpers():
     assert dispatch.rc_bucket(7, 900) == dispatch.rc_bucket(8, 1024)
     assert dispatch.rc_bucket(7, 900) != dispatch.rc_bucket(9, 900)
     assert dispatch.bucket_batch(1, 1) == 1
-    assert dispatch.bucket_batch(7, 4) == 8
+    assert dispatch.bucket_batch(7, 8) == 8
+    # TPU block rule: a multiple of 8 rows, or the whole padded batch
+    assert dispatch.batch_block(7, 4) == 8
+    assert dispatch.batch_block(3, 1) == 4
+    assert dispatch.batch_block(32, 4) == 8
+    assert dispatch.default_batch_block(1) == 1
+    assert dispatch.default_batch_block(4) == 4
+    assert dispatch.default_batch_block(32) == 8
+    with pytest.raises(ValueError, match="multiple of 8"):
+        dispatch.bucket_batch(7, 4)
 
 
 def test_row_reduction_autotune_per_bucket_pair(tmp_path):

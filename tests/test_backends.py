@@ -268,3 +268,94 @@ def test_xla_backend_renders_source_without_pallas():
     assert "pl." not in src and "_ref" not in src
     psrc = k.render(8, backend="pallas")
     assert "pl.program_id" in psrc or "_ref" in psrc
+
+
+# ------------------------------------------------- chip-facing helpers
+def test_interpret_flag_has_no_default():
+    """A spec must carry the interpret flag its family resolved: one
+    built without it is an error, not a silent interpreter run."""
+    from repro.core.backends.base import ReductionSpec
+    from repro.core.platform import interpret_mode
+
+    with pytest.raises(TypeError, match="interpret"):
+        ReductionSpec(name="r", arg_meta=(), scalar_names=(),
+                      loaded_vectors=(), prelude_lines=(), outs=(),
+                      multi=False)
+    k = ReductionKernel(np.float32, "0", "a+b", "x[i]", "float *x",
+                        name="bk_interp_flag")
+    assert k.spec.interpret is interpret_mode() is (jax.default_backend() != "tpu")
+    k(jnp.ones(300, jnp.float32), backend="pallas")
+    drivers = [dispatch.driver_cache().get(key)
+               for key in dispatch.driver_cache().keys() if key[1] == "pallas"]
+    assert drivers and all(d.interpret is interpret_mode() for d in drivers)
+    assert all(callable(d.call) for d in drivers)
+
+
+def test_device_peaks_table():
+    from repro.core.platform import PEAKS, TARGET_DEVICE_KIND, device_peaks
+
+    v5e = device_peaks("TPU v5 lite")
+    assert (v5e["bf16_flops"], v5e["hbm_bytes_per_s"]) == (197e12, 819e9)
+    # off the chip the analytic models score for the target chip
+    assert device_peaks() is PEAKS[TARGET_DEVICE_KIND]
+    with pytest.raises(KeyError, match="no peaks"):
+        device_peaks("TPU v99")
+
+
+def test_configure_compile_cache(monkeypatch):
+    from repro.core.platform import REPO_ROOT, configure_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert configure_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = configure_compile_cache()
+        assert path == str(REPO_ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+def test_row_prefix_sum_output(backend):
+    """``out[i] = cumsumf(e)``, the sampler's inverse-CDF epilogue: the
+    Pallas kernel computes ``e`` (Mosaic has no cumsum lowering) and its
+    driver prefix-sums the row inside the same launch."""
+    from repro.core.platform import BroadcastArg, VectorArg
+
+    k = ElementwiseKernel(
+        [BroadcastArg(np.float32, "r0", "row"), VectorArg(np.float32, "x"),
+         VectorArg(np.float32, "out")],
+        "out[i] = cumsumf(x[i] / r0)", name="bk_row_cdf", layout="rows",
+        backend=backend)
+    if backend == "pallas":
+        assert "cumsum" not in k.render(8, 1024, backend="pallas")
+    x = np.abs(rng.standard_normal((3, 1000))).astype(np.float32)
+    lens = [1000, 700, 5]
+    r0 = jnp.asarray([x[i, :n].sum() for i, n in enumerate(lens)])
+    k(r0, jnp.asarray(x), jnp.asarray(x), row_lens=lens)  # build
+    with dispatch.count_launches() as c:
+        out = np.asarray(k(r0, jnp.asarray(x), jnp.asarray(x), row_lens=lens))
+    assert c.by_backend == {backend: 1}
+    for i, n in enumerate(lens):
+        np.testing.assert_allclose(out[i, :n], np.cumsum(x[i, :n]) / r0[i],
+                                   rtol=1e-5, atol=1e-6)
+
+
+def test_pallas_cumsumf_must_be_a_whole_row_output():
+    from repro.core.backends.pallas import split_row_scans
+
+    line = ("out = jnp.broadcast_to(jnp.asarray(cumsum_lanes(x / r0)), "
+            "_BLK).astype(jnp.float32)")
+    body, scans = split_row_scans([line], ["out"])
+    assert scans == ["out"]
+    assert body == ["out = jnp.broadcast_to(jnp.asarray(x / r0), "
+                    "_BLK).astype(jnp.float32)"]
+    partial = line.replace("cumsum_lanes(x / r0)", "cumsum_lanes(x) / r0")
+    with pytest.raises(NotImplementedError, match="whole right-hand side"):
+        split_row_scans([partial], ["out"])
+    with pytest.raises(NotImplementedError, match="read or rewritten"):
+        split_row_scans([line, "out = out * 2"], ["out"])

@@ -323,3 +323,19 @@ def test_graceful_drain_and_rolling_restart_warm(tmp_path):
         assert st["fleet"]["failed"] == 0
     finally:
         fleet.close()
+
+
+def test_fleet_refuses_workers_that_need_a_held_chip(monkeypatch):
+    """On a TPU host the parent holds the chip: a fleet whose workers
+    would open the same platform fails at once, naming the cause."""
+    import repro.core.platform as platform
+    from repro.runtime.fleet import check_one_process_per_chip
+
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="one process per TPU chip"):
+        ServingFleet(workers=2, start=False)
+    # workers pinned off the chip are fine
+    check_one_process_per_chip(env={"JAX_PLATFORMS": "cpu"})
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    check_one_process_per_chip()
