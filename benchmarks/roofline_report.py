@@ -1,17 +1,10 @@
-"""Roofline reporting: the analytic dry-run table AND the *observed*
-launch profile from the PR 10 metrics plane.
-
-Analytic (the original §Roofline table, from cost-model dry runs)::
+"""Roofline reporting: the analytic dry-run table (the original
+§Roofline table, from cost-model dry runs)::
 
     PYTHONPATH=src python -m benchmarks.roofline_report [--mesh 16x16]
 
-Observed (per-(family, backend, bucket) bytes-moved / launch-time rows
-recorded by ``repro.runtime.observe`` while REPRO_TRACE was armed —
-realized GB/s per launch wave, the router's future energy/roofline
-axis)::
-
-    PYTHONPATH=src python -m benchmarks.roofline_report --observed stats.json
-    PYTHONPATH=src python -m benchmarks.run --roofline   # drive + render
+Measured roofline shares come from the chip benchmark's traces
+(``chipbench/metrics/prefill_roofline.py``, ``decode_roofline.py``).
 """
 
 from __future__ import annotations
@@ -37,47 +30,11 @@ def render(path="results/dryrun.json", mesh="16x16") -> str:
     return "\n".join(out)
 
 
-def render_observed(metrics_doc: "dict | None" = None) -> str:
-    """The observed launch-profile table: one row per (family, backend,
-    rc bucket) fold of the recorder's steady-state waves (compile-free,
-    degradation-free `_timed` calls) — calls, generated-kernel launches,
-    total wall seconds, bytes moved (read input + write output), and
-    the realized GB/s.  Pass a merged fleet metrics document to see the
-    whole fleet's profile; default is this process's live registry."""
-    from repro.runtime import observe
-
-    rows = observe.launch_profile(metrics_doc)
-    out = [f"{'family':16s} {'backend':8s} {'bucket':14s} {'calls':>7s} "
-           f"{'launch':>7s} {'ms':>9s} {'MiB':>9s} {'GB/s':>8s}"]
-    for r in rows:
-        out.append(
-            f"{r['family']:16s} {r['backend']:8s} {r['bucket']:14s} "
-            f"{r['calls']:7d} {r['launches']:7d} {r['seconds']*1e3:9.2f} "
-            f"{r['bytes']/2**20:9.2f} {r['gb_per_s']:8.3f}")
-    if not rows:
-        out.append("(no launch-profile rows — arm REPRO_TRACE=counters "
-                   "and serve some steady-state traffic first)")
-    return "\n".join(out)
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--path", default="results/dryrun.json")
     ap.add_argument("--mesh", default="16x16")
-    ap.add_argument("--observed", nargs="?", const="-", default=None,
-                    metavar="STATS_JSON",
-                    help="render the observed launch profile instead: "
-                         "from a saved stats_snapshot JSON (its "
-                         "'metrics' key), or the live process registry "
-                         "when no file is given")
     args = ap.parse_args()
-    if args.observed is not None:
-        doc = None
-        if args.observed != "-":
-            stats = json.loads(Path(args.observed).read_text())
-            doc = stats.get("metrics", stats)
-        print(render_observed(doc))
-        return
     print(render(args.path, args.mesh))
 
 

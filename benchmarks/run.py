@@ -95,34 +95,6 @@ def compare_rows(fresh: dict, committed: dict, tol: float = 0.20) -> list[str]:
     return problems
 
 
-def roofline_observed(k: int = 16, n: int = 2048) -> None:
-    """Drive one warm + one steady coalesced softmax wave with the
-    recorder in counters mode, then render the observed launch-profile
-    roofline table.  The warm wave pays the compiles; only the steady
-    (zero-compile, degradation-free) wave lands in the profile —
-    exactly the record_wave contract in `repro.runtime.observe`."""
-    import numpy as np
-
-    from benchmarks import bench_serving, roofline_report
-    from repro.runtime import observe
-
-    prev = observe.set_mode("counters")
-    try:
-        rng = np.random.default_rng(0)
-        rows = [rng.standard_normal(n).astype(np.float32) for _ in range(k)]
-        rt = bench_serving._fresh_runtime(k, f"roofline_obs_{k}x{n}")
-        try:
-            bench_serving._coalesced_wave(rt, rows)   # warm: compiles
-            bench_serving._coalesced_wave(rt, rows)   # steady: profiled
-        finally:
-            rt.close()
-        print(f"# observed launch profile ({k} requests x ({n},) rows, "
-              "steady wave):")
-        print(roofline_report.render_observed())
-    finally:
-        observe.set_mode(prev)
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="", help="comma list: table1,table2,...")
@@ -142,18 +114,10 @@ def main() -> None:
     ap.add_argument("--chaos", default="",
                     help="arm a process-lifetime transient fault plan, e.g. "
                          "compile:0.05,launch:0.05 (same spec as REPRO_CHAOS)")
-    ap.add_argument("--roofline", action="store_true",
-                    help="drive a short REPRO_TRACE=counters serving wave "
-                         "and print the observed launch-profile roofline "
-                         "table (benchmarks.roofline_report --observed)")
     args = ap.parse_args()
     from repro.core.platform import configure_compile_cache
 
     configure_compile_cache()
-
-    if args.roofline:
-        roofline_observed()
-        return
 
     if args.chaos:
         from repro.runtime import faults

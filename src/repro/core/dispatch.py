@@ -80,8 +80,8 @@ _fault_hook: "Callable | None" = None
 # same core-never-imports-runtime seam as the fault hook.  Events:
 # ``("site", site=, backend=, family=, bucket=, t0=, t1=)`` for a timed
 # compile/launch attempt (monotonic seconds), ``("degradation", rung=,
-# family=)`` per ladder step, and ``("begin",)``/``("end", token=,
-# name=, family=)`` bracketing an `observe_block`.  With no observer the
+# family=)`` per ladder step, and ``("begin", name=, family=)``/
+# ``("end", token=, name=, family=)`` bracketing an `observe_block`.  With no observer the
 # launch path pays one ``is None`` check and zero allocations.
 _observer: "Callable | None" = None
 
@@ -158,7 +158,8 @@ class _ObserveBlock:
         obs = _observer
         if obs is not None:
             try:
-                self.token = obs("begin")
+                self.token = obs("begin", name=self.name,
+                                 family=self.family)
             except Exception:  # pragma: no cover
                 self.token = None
         return self

@@ -137,20 +137,22 @@ def flash_attention_jnp(q, k, v, *, causal: bool, scale: float,
 def decode_attention(q, k_cache, v_cache, pos, *, scale: float):
     """Single-token decode. q: (B, 1, H, dh); caches: (B, Smax, Hk, dh);
     pos: () or (B,) int32 — number of valid cache entries minus one is
-    pos; positions <= pos attend."""
-    B, _, H, dh = q.shape
-    Smax, Hk = k_cache.shape[1], k_cache.shape[2]
-    g = H // Hk
-    qg = q.reshape(B, H, dh).astype(jnp.float32)
-    kf = k_cache.astype(jnp.float32)
-    s = jnp.einsum("bhd,bshd->bhs", qg,
-                   _gqa_expand(kf, H)) * scale            # (B, H, Smax)
-    col = jnp.arange(Smax)
-    valid = col[None, :] <= jnp.reshape(pos, (-1, 1))     # (B or 1, Smax)
-    s = jnp.where(valid[:, None, :], s, NEG_INF)
-    p = fused_softmax(s)
-    out = jnp.einsum("bhs,bshd->bhd", p, _gqa_expand(v_cache.astype(jnp.float32), H))
-    return out.reshape(B, 1, H, dh).astype(q.dtype)
+    pos; positions <= pos attend.  Its operations carry the
+    ``decode_attention`` scope in the compiled program's metadata."""
+    with jax.named_scope("decode_attention"):
+        B, _, H, dh = q.shape
+        Smax = k_cache.shape[1]
+        qg = q.reshape(B, H, dh).astype(jnp.float32)
+        kf = k_cache.astype(jnp.float32)
+        s = jnp.einsum("bhd,bshd->bhs", qg,
+                       _gqa_expand(kf, H)) * scale        # (B, H, Smax)
+        col = jnp.arange(Smax)
+        valid = col[None, :] <= jnp.reshape(pos, (-1, 1))  # (B or 1, Smax)
+        s = jnp.where(valid[:, None, :], s, NEG_INF)
+        p = fused_softmax(s)
+        out = jnp.einsum("bhs,bshd->bhd", p,
+                         _gqa_expand(v_cache.astype(jnp.float32), H))
+        return out.reshape(B, 1, H, dh).astype(q.dtype)
 
 
 def kv_sharded_decode_attention(cfg: ModelConfig, ctx, q, k_cache, v_cache,
@@ -164,7 +166,15 @@ def kv_sharded_decode_attention(cfg: ModelConfig, ctx, q, k_cache, v_cache,
     versus GSPMD's all-gather of the whole cache.
 
     q: (B, 1, H, dh); caches: (B, Smax, Hk, dh) seq-sharded; k_new/v_new:
-    (B, 1, Hk, dh). -> (out (B,1,H,dh), new_k_cache, new_v_cache)."""
+    (B, 1, Hk, dh). -> (out (B,1,H,dh), new_k_cache, new_v_cache).
+    Its operations carry the ``decode_attention`` scope."""
+    with jax.named_scope("decode_attention"):
+        return _kv_sharded_decode_attention(cfg, ctx, q, k_cache, v_cache,
+                                            k_new, v_new, pos)
+
+
+def _kv_sharded_decode_attention(cfg: ModelConfig, ctx, q, k_cache, v_cache,
+                                 k_new, v_new, pos):
     from jax.sharding import PartitionSpec as P
 
     B = q.shape[0]

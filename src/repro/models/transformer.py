@@ -68,10 +68,11 @@ def attn_mixer(cfg: ModelConfig, p, x, positions, ctx, *, causal=True,
             y, k_cache, v_cache = attn_mod.kv_sharded_decode_attention(
                 cfg, ctx, q, cache["k"], cache["v"], k, v, pos)
         else:
-            k_cache = lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
+            with jax.named_scope("kv_write"):
+                k_cache = lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
+                v_cache = lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
             y = attn_mod.decode_attention(q, k_cache, v_cache, pos,
                                           scale=cfg.dh ** -0.5)
         new_cache = {**cache, "k": k_cache, "v": v_cache}

@@ -102,46 +102,32 @@ class ServingRuntime:
             bucket = bucket + ("R",)
         be = self._resolve(family, bucket, backend)
         # telemetry (PR 10): a "serve" span parenting the plan/launch
-        # spans below it, a latency observation labeled (family, backend,
-        # bucket, rung), and a launch-profile row.  Every hook here is
-        # behind the REPRO_TRACE knob — off-mode adds one int check.
-        tok = observe.span_begin()
+        # spans below it, and a latency observation labeled (family,
+        # backend, bucket, rung).  Every hook here is behind the
+        # REPRO_TRACE knob (or an active profiler session, for the
+        # span) — off-mode adds one int check.
+        tok = observe.span_begin("serve", "runtime")
         if observe._MODE:
             dispatch.take_last_rung()   # clear a stale rung on this thread
-            lcm = dispatch.count_launches()
-        else:
-            lcm = dispatch._NULL_BLOCK
         d0 = dispatch.degradation_total()
         t0 = time.perf_counter()
         try:
-            with dispatch.count_compiles() as cc, lcm:
+            with dispatch.count_compiles() as cc:
                 out = run(be)
                 jax.block_until_ready(out)
         finally:
             if tok is not None:
-                observe.span_end(tok, "serve", "runtime",
-                                 {"family": family, "backend": be,
-                                  "bucket": str(bucket)})
+                observe.span_end(tok, args={"family": family, "backend": be,
+                                            "bucket": str(bucket)})
         dt = time.perf_counter() - t0
         if observe._MODE:
-            clean0 = dispatch.degradation_total() == d0
             rung = dispatch.take_last_rung() or (
-                "none" if clean0 else "degraded")
+                "none" if dispatch.degradation_total() == d0
+                else "degraded")
             bstr = "x".join(str(d) for d in bucket)
             observe.observe_hist("request_latency_seconds",
                                  (family, be, bstr, rung), dt)
             observe.count("requests_total", family, be)
-            if cc.delta == 0 and clean0:
-                # steady-state wave (no one-off builds, no ladder): fold
-                # into the roofline launch profile.  Bytes moved is the
-                # read-input + write-output estimate for the 2-launch
-                # row schedule; intermediates are O(rows), negligible.
-                elems = 1
-                for d in geometry:
-                    elems *= int(d)
-                observe.record_wave(family, be, bstr, dt,
-                                    2 * elems * np.dtype(dtype).itemsize,
-                                    getattr(lcm, "delta", 0))
         if record:
             # cold calls pay one-off driver builds; folding that wall-clock
             # into the EMA would poison the route (compile cost is

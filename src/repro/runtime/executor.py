@@ -260,59 +260,75 @@ class CoalescingExecutor:
         # thread BEFORE _run_batch so the runtime's "serve" span — and
         # the plan/launch spans below it — parent under this flush;
         # per-request spans are reconstructed post-hoc from the batch's
-        # recorded submit timestamps (zero bookkeeping on submit).
-        ftok = observe.span_begin()
+        # recorded submit timestamps (zero bookkeeping on submit).  It
+        # closes exactly once, in the finally, whatever the flush raises:
+        # with a profiler session on it is an annotation that must end
+        # on this thread.
+        ftok = observe.span_begin("flush", "executor")
         t_flush = time.monotonic()
+        isolated = False
         try:
-            self._probe_rows(batch)  # injected poison fails the flush here
-            lens = None
-            if batch.ragged:
-                lens = [int(r.shape[0]) for r in batch.rows]
-                width = max(lens)
-                X = jnp.stack([
-                    r if int(r.shape[0]) == width
-                    else jnp.pad(r, (0, width - int(r.shape[0])))
-                    for r in batch.rows])
-            else:
-                X = jnp.stack(batch.rows)
-            with dispatch.count_launches() as c:
-                out = self._runtime._run_batch(batch.family, X, batch.shared,
-                                               row_lens=lens)
-            with self._cv:
-                self._flushes += 1
-                if len(batch.rows) >= self.max_batch:
-                    self._full_flushes += 1
-                else:
-                    self._window_flushes += 1
-                self._launches += c.delta
-                self._max_coalesce = max(self._max_coalesce, len(batch.rows))
-        except BaseException as e:  # noqa: BLE001 - batch failed: isolate
-            # Poison-request isolation (DESIGN.md §10): one bad request
-            # must not take down its K-1 co-travellers, so the batch
-            # falls back to bounded per-row retries (whose serve spans
-            # still parent under this flush span — it closes after).
-            self._retry_rows(batch, e)
-            observe.span_end(ftok, "flush", "executor",
-                             {"family": batch.family,
-                              "rows": len(batch.rows), "isolated": True})
-            self._note_flush(batch, t_flush)
-            return
-        t_out = time.monotonic()
-        # scatter results; a failing per-request post step (e.g. a bad
-        # sampler key) fails ONLY its own future, never co-batched ones.
-        # Ragged rows resolve with their true-length prefix (the padding
-        # columns are masked to zero in-kernel and carry no information).
-        for i, (fut, post) in enumerate(zip(batch.futures, batch.posts)):
             try:
-                row_out = out[i] if lens is None else out[i][:lens[i]]
-                fut._set(post(row_out) if post is not None else row_out)
-            except BaseException as e:  # noqa: BLE001
-                fut._set_error(e)
-        flush_sid = observe.span_end(
-            ftok, "flush", "executor",
-            {"family": batch.family, "rows": len(batch.rows)})
+                self._probe_rows(batch)  # injected poison fails here
+                lens = None
+                if batch.ragged:
+                    lens = [int(r.shape[0]) for r in batch.rows]
+                    width = max(lens)
+                    X = jnp.stack([
+                        r if int(r.shape[0]) == width
+                        else jnp.pad(r, (0, width - int(r.shape[0])))
+                        for r in batch.rows])
+                else:
+                    X = jnp.stack(batch.rows)
+                with dispatch.count_launches() as c:
+                    out = self._runtime._run_batch(batch.family, X,
+                                                   batch.shared,
+                                                   row_lens=lens)
+                with self._cv:
+                    self._flushes += 1
+                    if len(batch.rows) >= self.max_batch:
+                        self._full_flushes += 1
+                    else:
+                        self._window_flushes += 1
+                    self._launches += c.delta
+                    self._max_coalesce = max(self._max_coalesce,
+                                             len(batch.rows))
+            except BaseException as e:  # noqa: BLE001 - batch failed
+                # Poison-request isolation (DESIGN.md §10): one bad
+                # request must not take down its K-1 co-travellers, so
+                # the batch falls back to bounded per-row retries (whose
+                # serve spans still parent under this flush span).
+                isolated = True
+                self._retry_rows(batch, e)
+            else:
+                t_out = time.monotonic()
+                # scatter results; a failing per-request post step (e.g.
+                # a bad sampler key) fails ONLY its own future, never
+                # co-batched ones.  Ragged rows resolve with their true-
+                # length prefix (the padding columns are masked to zero
+                # in-kernel and carry no information).
+                rtok = observe.span_begin("reply", "executor")
+                try:
+                    for i, (fut, post) in enumerate(zip(batch.futures,
+                                                        batch.posts)):
+                        try:
+                            row_out = out[i] if lens is None \
+                                else out[i][:lens[i]]
+                            fut._set(post(row_out) if post is not None
+                                     else row_out)
+                        except BaseException as e:  # noqa: BLE001
+                            fut._set_error(e)
+                finally:
+                    observe.span_end(rtok)
+        finally:
+            args = None
+            if ftok is not None:
+                args = {"family": batch.family, "rows": len(batch.rows)}
+                if isolated:
+                    args["isolated"] = True
+            flush_sid = observe.span_end(ftok, args=args)
         self._note_flush(batch, t_flush)
-        if flush_sid is not None:
+        if flush_sid is not None and not isolated:
             self._record_request_spans(batch, t_flush, t_out, flush_sid)
 
     def _note_flush(self, batch: _Batch, t_flush: float) -> None:

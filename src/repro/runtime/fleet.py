@@ -149,7 +149,7 @@ def _worker_main(conn, config: dict) -> None:
             # dispatcher-side "dispatch" span joins on via the shared
             # gid (monotonic timestamps are system-wide, so the merged
             # trace lines up across pids without clock translation)
-            stok = observe.span_begin()
+            stok = observe.span_begin("serve_group", "fleet")
             try:
                 faults.worker_fault(family=family, index=groups)
                 out = np.asarray(
@@ -164,10 +164,10 @@ def _worker_main(conn, config: dict) -> None:
                 reply = ("res", gid, False, f"{type(e).__name__}: {e}")
             finally:
                 if stok is not None:
-                    observe.span_end(stok, "serve_group", "fleet",
-                                     {"gid": gid, "family": family,
-                                      "rows": len(rows),
-                                      "ok": reply[2]})
+                    observe.span_end(stok, args={"gid": gid,
+                                                 "family": family,
+                                                 "rows": len(rows),
+                                                 "ok": reply[2]})
             try:
                 conn.send(reply)
             except (OSError, EOFError, BrokenPipeError):

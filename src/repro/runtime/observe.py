@@ -8,14 +8,13 @@ stack" and owns two cooperating planes:
 
   * the **flight recorder** — per-request spans (fleet admit → queue
     wait → coalesced flush → compile/launch per backend → sampler →
-    reply, plus ContinuousEngine decode steps) in a bounded ring
+    reply, plus ContinuousEngine steps and their phases) in a bounded ring
     buffer, exportable as Chrome trace-event JSON (`export_trace`,
     loadable in Perfetto / ``chrome://tracing``);
   * the **metrics plane** — fixed-bucket latency/size histograms with
     p50/p95/p99, labeled ``(family, backend, rc_bucket, rung)``, plus
-    event counters and a per-(family, backend, bucket) launch profile
-    (bytes moved / launch seconds — the roofline report's input).
-    Fixed bucket edges make the merge a plain elementwise count sum:
+    event counters.  Fixed bucket edges make the merge a plain
+    elementwise count sum:
     associative, commutative, and exact, so `merge_metrics` folds N
     fleet workers into ONE coherent percentile view (accurate to one
     bucket width).
@@ -30,6 +29,14 @@ Everything is gated by one process-wide knob::
 single module-int check and `dispatch.set_observer(None)` means the
 core launch path never even calls back here.  The overhead bound is
 benchmarked and gated in ``benchmarks/bench_obs.py``.
+
+Whatever the knob, a scoped span (`span_begin`/`span_end`, `span`)
+that opens while a JAX profiler session is active also becomes a
+``jax.profiler.TraceAnnotation`` named ``<cat>.<name>``: it lands in
+the profile's host plane on the device planes' clock, so device idle
+time in a profile names the serving phase the host was in.  Spans the
+recorder builds after the fact from stored timestamps stay
+recorder-only.  JAX is imported on the first named span, not here.
 
 The core never imports this module — `install()` injects a callback
 through `dispatch.set_observer` (the PR 6 ``set_fault_hook`` pattern),
@@ -63,7 +70,7 @@ _MODE_NAMES = {"off": MODE_OFF, "counters": MODE_COUNTERS,
 #: global load (hot paths read ``observe._MODE`` directly)
 _MODE = MODE_OFF
 
-TRACE_CAPACITY = int(os.environ.get("REPRO_TRACE_CAPACITY", "65536"))
+TRACE_CAPACITY = 65536      # flight-recorder ring size (events)
 
 # ---------------------------------------------------------------- histograms
 #: fixed log2-spaced latency edges (seconds), 1µs .. ~33s.  FIXED edges
@@ -85,6 +92,8 @@ HIST_DEFS: dict = {
     "flush_rows": (("family",), SIZE_EDGES),
     "launch_seconds": (("site", "backend"), LATENCY_EDGES_S),
     "decode_step_seconds": ((), LATENCY_EDGES_S),
+    "engine_queue_wait_seconds": ((), LATENCY_EDGES_S),
+    "engine_live_rows": ((), SIZE_EDGES),
 }
 COUNTER_DEFS: dict = {
     "requests_total": ("family", "backend"),
@@ -147,7 +156,7 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Thread-safe label-keyed histograms + counters + launch profile.
+    """Thread-safe label-keyed histograms + counters.
 
     Keys are ``(metric, (label values...))``; label *names* live in
     `HIST_DEFS`/`COUNTER_DEFS`.  `snapshot()` is the JSON-able document
@@ -158,8 +167,6 @@ class MetricsRegistry:
         self._lock = threading.Lock()
         self._hists: dict = {}
         self._counters: dict = {}
-        #: (family, backend, bucket) -> [calls, launches, seconds, bytes]
-        self._profile: dict = {}
 
     def observe(self, metric: str, labels: tuple, value: float) -> None:
         with self._lock:
@@ -174,19 +181,6 @@ class MetricsRegistry:
             k = (metric, labels)
             self._counters[k] = self._counters.get(k, 0) + n
 
-    def wave(self, family: str, backend: str, bucket: str,
-             seconds: float, nbytes: int, launches: int) -> None:
-        """Fold one timed launch wave into the roofline profile."""
-        with self._lock:
-            row = self._profile.get((family, backend, bucket))
-            if row is None:
-                row = self._profile[(family, backend, bucket)] = \
-                    [0, 0, 0.0, 0]
-            row[0] += 1
-            row[1] += launches
-            row[2] += seconds
-            row[3] += nbytes
-
     def snapshot(self) -> dict:
         with self._lock:
             hists: dict = {}
@@ -197,26 +191,20 @@ class MetricsRegistry:
             for (metric, labels), n in self._counters.items():
                 counters.setdefault(metric, {})[
                     _LSEP.join(str(v) for v in labels)] = n
-            profile = {
-                _LSEP.join(k): {"calls": v[0], "launches": v[1],
-                                "seconds": v[2], "bytes": v[3]}
-                for k, v in self._profile.items()}
-        return {"histograms": hists, "counters": counters,
-                "profile": profile}
+        return {"histograms": hists, "counters": counters}
 
     def clear(self) -> None:
         with self._lock:
             self._hists.clear()
             self._counters.clear()
-            self._profile.clear()
 
 
 def merge_metrics(*docs: "dict | None") -> dict:
     """Merge metrics-snapshot documents: histogram counts and counters
-    sum elementwise, profile rows sum field-wise.  Associative and
-    commutative (fixed edges; pure addition), so any merge order across
-    the fleet yields the same document."""
-    out: dict = {"histograms": {}, "counters": {}, "profile": {}}
+    sum elementwise.  Associative and commutative (fixed edges; pure
+    addition), so any merge order across the fleet yields the same
+    document."""
+    out: dict = {"histograms": {}, "counters": {}}
     for doc in docs:
         if not isinstance(doc, dict):
             continue
@@ -238,12 +226,6 @@ def merge_metrics(*docs: "dict | None") -> dict:
             dst_m = out["counters"].setdefault(metric, {})
             for lkey, n in series.items():
                 dst_m[lkey] = dst_m.get(lkey, 0) + n
-        for lkey, row in (doc.get("profile") or {}).items():
-            dst = out["profile"].setdefault(
-                lkey, {"calls": 0, "launches": 0, "seconds": 0.0,
-                       "bytes": 0})
-            for f in ("calls", "launches", "seconds", "bytes"):
-                dst[f] += row.get(f, 0)
     return out
 
 
@@ -274,26 +256,6 @@ def latency_summary(metrics_doc: "dict | None") -> dict:
                    "p95_ms": h.percentile(0.95) * 1e3,
                    "p99_ms": h.percentile(0.99) * 1e3}
     return out
-
-
-def launch_profile(metrics_doc: "dict | None" = None) -> list[dict]:
-    """Roofline input rows: per-(family, backend, bucket) launch
-    profile with realized GB/s, from a metrics document (default: this
-    process's live registry)."""
-    doc = metrics_doc if metrics_doc is not None else METRICS.snapshot()
-    rows = []
-    for lkey, row in sorted((doc.get("profile") or {}).items()):
-        parts = lkey.split(_LSEP)
-        family, backend = parts[0], parts[1] if len(parts) > 1 else "?"
-        bucket = _LSEP.join(parts[2:])
-        sec = float(row.get("seconds", 0.0))
-        rows.append({
-            "family": family, "backend": backend, "bucket": bucket,
-            "calls": row.get("calls", 0), "launches": row.get("launches", 0),
-            "seconds": sec, "bytes": row.get("bytes", 0),
-            "gb_per_s": (row.get("bytes", 0) / sec / 2**30) if sec else 0.0,
-        })
-    return rows
 
 
 # ----------------------------------------------------------- text exposition
@@ -426,34 +388,71 @@ def current_parent() -> "int | None":
     return stack[-1] if stack else None
 
 
-def span_begin() -> "tuple | None":
-    """Open a span and push it as the current thread's parent; returns
-    an opaque token for `span_end` (None when spans are off — the
-    off/counters fast path is one global check and no allocation)."""
+def _bind_profiler() -> bool:
+    """First named span: bind the profiler's annotation lazily (JAX is
+    not imported with this module) and answer for this call."""
+    global _profiling, _Annotation
+    from jax.profiler import TraceAnnotation
+
+    _Annotation = TraceAnnotation
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
+
+
+#: is a JAX profiler session recording?  (rebound on first use)
+_profiling = _bind_profiler
+_Annotation: Any = None
+
+
+def span_begin(name: "str | None" = None, cat: str = "",
+               args: "dict | None" = None) -> "tuple | None":
+    """Open a span; returns an opaque token for `span_end`.
+
+    In spans mode the span is pushed as the current thread's parent and
+    recorded at its end.  While a profiler session is active a named
+    span is also a ``TraceAnnotation`` ``<cat>.<name>`` (with ``args`` as
+    its metadata), closed by `span_end` on the same thread.  With
+    neither on this returns None without allocating."""
+    ann = None
+    if name is not None and _profiling():
+        ann = _Annotation(f"{cat}.{name}", **(args or {}))
+        ann.__enter__()
     if _MODE < MODE_SPANS:
-        return None
+        return None if ann is None else (None, None, 0.0, name, cat, args,
+                                         ann)
     sid = RECORDER.next_id()
     stack = getattr(_ctx, "stack", None)
     if stack is None:
         stack = _ctx.stack = []
     parent = stack[-1] if stack else None
     stack.append(sid)
-    return (sid, parent, time.monotonic())
+    return (sid, parent, time.monotonic(), name, cat, args, ann)
 
 
-def span_end(token: "tuple | None", name: str, cat: str,
+def span_end(token: "tuple | None", name: "str | None" = None,
+             cat: "str | None" = None,
              args: "dict | None" = None) -> "int | None":
-    """Close a span opened by `span_begin` (no-op on a None token).
-    Callers pair these in try/finally so an exception can't leak the
-    parent stack."""
+    """Close a span opened by `span_begin` (no-op on a None token);
+    ``name``/``cat`` default to the ones given at the begin, ``args``
+    add to its.  Returns the recorded span id (spans mode).  Callers
+    pair these in try/finally so an exception can't leak the parent
+    stack or leave an annotation open."""
     if token is None:
         return None
-    sid, parent, t0 = token
+    sid, parent, t0, name0, cat0, args0, ann = token
+    if ann is not None:
+        if args:
+            ann.set_metadata(**args)
+        ann.__exit__(None, None, None)
+    if sid is None:
+        return None
     stack = getattr(_ctx, "stack", None)
     if stack and stack[-1] == sid:
         stack.pop()
-    return RECORDER.add(name, cat, t0, time.monotonic(), sid=sid,
-                        parent=parent, args=args)
+    if args0:
+        args = {**args0, **args} if args else args0
+    return RECORDER.add(name or name0 or "span", cat or cat0, t0,
+                        time.monotonic(), sid=sid, parent=parent, args=args)
 
 
 class span:
@@ -468,13 +467,13 @@ class span:
         self.sid: "int | None" = None
 
     def __enter__(self) -> "span":
-        self.token = span_begin()
+        self.token = span_begin(self.name, self.cat, self.args or None)
         if self.token is not None:
             self.sid = self.token[0]
         return self
 
     def __exit__(self, *exc) -> bool:
-        span_end(self.token, self.name, self.cat, self.args or None)
+        span_end(self.token)
         return False
 
 
@@ -489,13 +488,6 @@ def observe_hist(metric: str, labels: tuple, value: float) -> None:
     """Record one histogram observation (no-op when the knob is off)."""
     if _MODE:
         METRICS.observe(metric, labels, value)
-
-
-def record_wave(family: str, backend: str, bucket: str, seconds: float,
-                nbytes: int, launches: int) -> None:
-    """Fold one timed launch wave into the roofline profile (no-op off)."""
-    if _MODE:
-        METRICS.wave(family, backend, bucket, seconds, nbytes, launches)
 
 
 # -------------------------------------------------- the dispatch observer
@@ -528,10 +520,10 @@ def _dispatch_event(event: str, site: "str | None" = None,
     elif event == "degradation":
         METRICS.inc("degradations_total", (rung or "?", family or "?"))
     elif event == "begin":
-        return span_begin()
+        return span_begin(name or "block", "plan",
+                          {"family": family} if family else None)
     elif event == "end":
-        span_end(token, name or "block", "plan",
-                 {"family": family} if family else None)
+        span_end(token)
     return None
 
 
@@ -688,14 +680,6 @@ def top_view(stats_doc: dict) -> str:
     if rungs:
         lines.append("degradations: " + ", ".join(
             f"{k}={v}" for k, v in sorted(rungs.items())))
-    prof = launch_profile(metrics_doc)
-    if prof:
-        lines.append(f"{'launch profile':<24s} {'calls':>8s} "
-                     f"{'launches':>9s} {'GB/s':>9s}")
-        for r in prof:
-            lines.append(f"{r['family'] + '|' + r['backend']:<24s} "
-                         f"{r['calls']:>8d} {r['launches']:>9d} "
-                         f"{r['gb_per_s']:>9.3f}")
     return "\n".join(lines)
 
 

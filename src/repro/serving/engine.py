@@ -222,7 +222,8 @@ class ContinuousEngine:
         self.pos = 0                      # uniform filled-column count
         self._slots: list = [None] * self.capacity   # slot -> _LiveRequest
         self._tok = np.full((self.capacity, 1), self.pad_id, np.int32)
-        self._pending: deque = deque()    # (rid, prompt, max_new, deadline)
+        # (rid, prompt, max_new, deadline, submit time)
+        self._pending: deque = deque()
         self._next_id = 0
         self._key = jax.random.PRNGKey(0)
         self._steps = 0
@@ -269,7 +270,8 @@ class ContinuousEngine:
         if request_id is None:
             request_id = self._next_id
         self._next_id = max(self._next_id, request_id) + 1
-        self._pending.append((request_id, prompt, int(max_new), deadline))
+        self._pending.append((request_id, prompt, int(max_new), deadline,
+                              time.monotonic()))
         return request_id
 
     # -- the decode loop --------------------------------------------------
@@ -296,10 +298,11 @@ class ContinuousEngine:
         """FIFO admission: lease slots to queued prompts that fit the
         current uniform position (an empty batch re-anchors the position
         to the first prompt's length).  Each admission is one fixed-
-        shape ``(1, max_len)`` prefill + one scatter; its first-token
-        logits row joins this step's sampler flush in ``rows``."""
+        shape ``(1, max_len)`` prefill + one scatter (an ``admit`` span);
+        its first-token logits row joins this step's sampler flush in
+        ``rows``."""
         while self._pending and self.kv.has_free_slot():
-            rid, prompt, max_new, deadline = self._pending[0]
+            rid, prompt, max_new, deadline, t_submit = self._pending[0]
             L = int(prompt.shape[0])
             if not self._live_slots() and not rows:
                 self.pos = L           # empty batch: re-anchor the clock
@@ -308,36 +311,54 @@ class ContinuousEngine:
             if self.pos >= self.max_len:
                 break                  # no room to decode even one token
             self._pending.popleft()
+            if observe._MODE:
+                observe.observe_hist("engine_queue_wait_seconds", (),
+                                     time.monotonic() - t_submit)
             slot = self.kv.admit(rid, L, deadline=deadline)
-            self._slots[slot] = _LiveRequest(rid, prompt, max_new)
-            toks = np.full((1, self.max_len), self.pad_id, np.int32)
-            toks[0, self.pos - L:self.pos] = prompt
-            logits1, row_cache = self._admit(
-                self.params, jnp.asarray(toks), jnp.int32(self.pos - 1))
-            self.cache = self._scatter(self.cache, row_cache,
-                                       jnp.int32(slot))
-            rows[slot] = logits1[0]
+            tok = observe.span_begin("admit", "engine")
+            try:
+                self._slots[slot] = _LiveRequest(rid, prompt, max_new)
+                toks = np.full((1, self.max_len), self.pad_id, np.int32)
+                toks[0, self.pos - L:self.pos] = prompt
+                logits1, row_cache = self._admit(
+                    self.params, jnp.asarray(toks), jnp.int32(self.pos - 1))
+                self.cache = self._scatter(self.cache, row_cache,
+                                           jnp.int32(slot))
+                rows[slot] = logits1[0]
+            finally:
+                observe.span_end(tok, args=None if tok is None else
+                                 {"prompt_len": L, "slot": slot})
 
     def _sample_rows(self, rows: dict, temperature: float) -> dict:
         """One token per live row — ONE ragged runtime flush when a
         runtime is attached and temperature > 0 (2 generated launches
-        for the whole step), host argmax for greedy decoding."""
+        for the whole step), host argmax for greedy decoding.  A
+        ``sample`` span, whose ``path`` names which."""
         if not rows:
             return {}
-        if temperature == 0.0:
-            return {s: int(np.argmax(np.asarray(r))) for s, r in rows.items()}
-        subkeys = {}
-        for s in sorted(rows):
-            self._key, subkeys[s] = jax.random.split(self._key)
-        if self.runtime is not None:
-            futs = {s: self.runtime.submit_sample(rows[s], subkeys[s],
-                                                  temperature)
-                    for s in sorted(rows)}
-            self.runtime.flush()
-            return {s: int(f.result(timeout=60.0)) for s, f in futs.items()}
-        return {s: int(jax.random.categorical(
-            subkeys[s], jnp.asarray(rows[s]) / temperature))
-            for s in sorted(rows)}
+        path = "greedy" if temperature == 0.0 else \
+            "runtime" if self.runtime is not None else "categorical"
+        tok = observe.span_begin("sample", "engine")
+        try:
+            if path == "greedy":
+                return {s: int(np.argmax(np.asarray(r)))
+                        for s, r in rows.items()}
+            subkeys = {}
+            for s in sorted(rows):
+                self._key, subkeys[s] = jax.random.split(self._key)
+            if path == "runtime":
+                futs = {s: self.runtime.submit_sample(rows[s], subkeys[s],
+                                                      temperature)
+                        for s in sorted(rows)}
+                self.runtime.flush()
+                return {s: int(f.result(timeout=60.0))
+                        for s, f in futs.items()}
+            return {s: int(jax.random.categorical(
+                subkeys[s], jnp.asarray(rows[s]) / temperature))
+                for s in sorted(rows)}
+        finally:
+            observe.span_end(tok, args=None if tok is None else
+                             {"rows": len(rows), "path": path})
 
     def step(self, temperature: float = 0.0) -> int:
         """One uniform decode step: evict expired leases, advance every
@@ -345,10 +366,11 @@ class ContinuousEngine:
         sample all fresh logits rows in one flush.  Returns the number
         of live requests after the step.
 
-        Each step is a ``decode_step`` span + latency observation
-        (PR 10) — the continuous-batching analogue of the executor's
-        flush span; the sampler's ragged flush parents under it."""
-        tok = observe.span_begin()
+        Each step is a ``step`` span (``engine.step`` in a profile) +
+        latency observation (PR 10), holding one span per phase:
+        ``decode``, ``admit`` (one per admission), ``sample`` (the
+        sampler's ragged flush parents under it) and ``retire``."""
+        tok = observe.span_begin("step", "engine")
         t0 = time.perf_counter()
         try:
             return self._step(temperature)
@@ -357,9 +379,8 @@ class ContinuousEngine:
                 observe.observe_hist("decode_step_seconds", (),
                                      time.perf_counter() - t0)
             if tok is not None:
-                observe.span_end(tok, "decode_step", "engine",
-                                 {"live": len(self._live_slots()),
-                                  "step": self._steps})
+                observe.span_end(tok, args={"live": len(self._live_slots()),
+                                            "step": self._steps})
 
     def _step(self, temperature: float = 0.0) -> int:
         for rid in self.kv.expired():
@@ -369,28 +390,38 @@ class ContinuousEngine:
         rows: dict = {}
         live = self._live_slots()
         if live:
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(self._tok),
-                jnp.int32(self.pos))
-            self.pos += 1
-            for s in live:
-                rows[s] = logits[s]
+            tok = observe.span_begin("decode", "engine")
+            try:
+                logits, self.cache = self._decode(
+                    self.params, self.cache, jnp.asarray(self._tok),
+                    jnp.int32(self.pos))
+                self.pos += 1
+                for s in live:
+                    rows[s] = logits[s]
+            finally:
+                observe.span_end(tok)
         self._admit_pending(rows)
         toks = self._sample_rows(rows, temperature)
-        self._steps += 1
-        self._generated += len(toks)
-        for s, t in toks.items():
-            req = self._slots[s]
-            req.tokens.append(t)
-            self._tok[s, 0] = t
-            if (len(req.tokens) >= req.max_new
-                    or (self.eos_id is not None and t == self.eos_id)):
-                self._finish(s)
-        if self.pos >= self.max_len:
-            # cache exhausted: every survivor ends truncated at max_len
-            for s in self._live_slots():
-                self._finish(s)
-        return len(self._live_slots())
+        if observe._MODE:
+            observe.observe_hist("engine_live_rows", (), float(len(toks)))
+        tok = observe.span_begin("retire", "engine")
+        try:
+            self._steps += 1
+            self._generated += len(toks)
+            for s, t in toks.items():
+                req = self._slots[s]
+                req.tokens.append(t)
+                self._tok[s, 0] = t
+                if (len(req.tokens) >= req.max_new
+                        or (self.eos_id is not None and t == self.eos_id)):
+                    self._finish(s)
+            if self.pos >= self.max_len:
+                # cache exhausted: every survivor ends truncated at max_len
+                for s in self._live_slots():
+                    self._finish(s)
+            return len(self._live_slots())
+        finally:
+            observe.span_end(tok)
 
     def run(self, temperature: float = 0.0, max_steps: int = 100000) -> list:
         """Step until the pending queue and the live batch drain; ->

@@ -159,6 +159,67 @@ def test_pending_queue_sheds(model):
     assert eng.stats()["pending_shed"] == 1
 
 
+def test_engine_counters_queue_wait_and_live_rows(model):
+    """Counters mode: one queue-wait observation per admitted request
+    (submit to the admission that pops it) and one live-rows observation
+    per step, summing to the tokens generated."""
+    from repro.runtime import observe
+
+    cfg, params = model
+    prev = observe.set_mode("counters")
+    observe.METRICS.clear()
+    try:
+        eng = ContinuousEngine(cfg, params, capacity=2, max_len=48)
+        for L, m in ((5, 3), (4, 2), (6, 2)):
+            eng.submit(_prompt(cfg, L), max_new=m)
+        eng.run()
+        hists = observe.METRICS.snapshot()["histograms"]
+    finally:
+        observe.set_mode(prev)
+        observe.METRICS.clear()
+    wait = hists["engine_queue_wait_seconds"][""]
+    rows = hists["engine_live_rows"][""]
+    assert wait["count"] == 3 and wait["sum"] >= 0.0
+    st = eng.stats()
+    assert rows["count"] == st["steps"]
+    assert rows["sum"] == st["tokens_generated"] == 7
+    text = observe.metrics_text({"histograms": hists})
+    assert f"repro_engine_live_rows_count {st['steps']}" in text
+    assert "# TYPE repro_engine_queue_wait_seconds histogram" in text
+
+
+def test_named_scopes_leave_the_decode_program_unchanged(model, monkeypatch):
+    """``decode_attention``/``kv_write`` are op metadata only: with the
+    scopes made no-ops the compiled decode step is the same program,
+    operation names included, once metadata is stripped."""
+    import contextlib
+    import re
+
+    from repro.models import transformer
+
+    cfg, params = model
+    cache = transformer.init_cache(cfg, 2, 32)
+    args = (params, cache, jnp.zeros((2, 1), jnp.int32), jnp.int32(4))
+
+    def compiled():   # a fresh function: no trace cached from before
+        text = jax.jit(lambda p, c, t, pos: transformer.decode_step(
+            cfg, p, c, t, pos)).lower(*args).compile().as_text()
+        # drop each instruction's metadata and the source-location tables
+        bare = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+        return text, [line for line in bare.splitlines() if line.strip()
+                      and not re.match(r"(FileNames|FunctionNames|"
+                                       r"FileLocations|StackFrames|\d+ )",
+                                       line)]
+
+    scoped, plain = compiled()
+    assert "/decode_attention/" in scoped and "/kv_write/" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    unscoped, plain_too = compiled()
+    assert "/decode_attention/" not in unscoped
+    assert plain == plain_too
+
+
 def test_rejects_non_attention_archs(model):
     cfg = get_config("rwkv6-7b", smoke=True).replace(dtype="float32")
     with pytest.raises(ValueError):
