@@ -135,23 +135,27 @@ def flash_attention_jnp(q, k, v, *, causal: bool, scale: float,
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, scale: float):
-    """Single-token decode. q: (B, 1, H, dh); caches: (B, Smax, Hk, dh);
-    pos: () or (B,) int32 — number of valid cache entries minus one is
-    pos; positions <= pos attend.  Its operations carry the
-    ``decode_attention`` scope in the compiled program's metadata."""
+    """Single-token grouped-query decode. q: (B, 1, H, dh); caches:
+    (B, Smax, Hk, dh) with Hk dividing H; pos: () or (B,) int32 —
+    number of valid cache entries minus one is pos; positions <= pos
+    attend.  Query head h reads KV head h // (H/Hk) from the cache in
+    its own dtype: no upcast or head-repeated copy of the cache, the
+    products accumulate in float32 and the probabilities stay float32.
+    Its operations carry the ``decode_attention`` scope in the compiled
+    program's metadata."""
     with jax.named_scope("decode_attention"):
         B, _, H, dh = q.shape
-        Smax = k_cache.shape[1]
-        qg = q.reshape(B, H, dh).astype(jnp.float32)
-        kf = k_cache.astype(jnp.float32)
-        s = jnp.einsum("bhd,bshd->bhs", qg,
-                       _gqa_expand(kf, H)) * scale        # (B, H, Smax)
+        Smax, Hk = k_cache.shape[1], k_cache.shape[2]
+        qg = q.reshape(B, Hk, H // Hk, dh)
+        s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
+                       preferred_element_type=jnp.float32) * scale
         col = jnp.arange(Smax)
         valid = col[None, :] <= jnp.reshape(pos, (-1, 1))  # (B or 1, Smax)
-        s = jnp.where(valid[:, None, :], s, NEG_INF)
+        s = jnp.where(valid[:, None, None, :], s, NEG_INF)  # (B, Hk, g, Smax)
         p = fused_softmax(s)
-        out = jnp.einsum("bhs,bshd->bhd", p,
-                         _gqa_expand(v_cache.astype(jnp.float32), H))
+        out = jnp.einsum("bkgs,bskd->bkgd", p, v_cache,
+                         precision=lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
         return out.reshape(B, 1, H, dh).astype(q.dtype)
 
 

@@ -220,6 +220,101 @@ def test_named_scopes_leave_the_decode_program_unchanged(model, monkeypatch):
     assert plain == plain_too
 
 
+# ------------------------------------------- grouped-query decode attention
+def _expanded_decode_attention(q, k_cache, v_cache, pos, scale):
+    """The decode formula before grouped-query attention, kept as the
+    oracle: float32 upcast, KV heads repeated H/Hk times, plain softmax.
+    -> float32 (B, 1, H, dh)."""
+    B, _, H, dh = q.shape
+    rep = H // k_cache.shape[2]
+    k = jnp.repeat(k_cache.astype(jnp.float32), rep, axis=2)
+    v = jnp.repeat(v_cache.astype(jnp.float32), rep, axis=2)
+    s = jnp.einsum("bhd,bshd->bhs",
+                   q.reshape(B, H, dh).astype(jnp.float32), k) * scale
+    valid = jnp.arange(k.shape[1])[None, :] <= jnp.reshape(pos, (-1, 1))
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhs,bshd->bhd", p, v).reshape(B, 1, H, dh)
+
+
+@pytest.mark.parametrize("jit", [True, False], ids=["jit", "concrete"])
+@pytest.mark.parametrize("pos_kind", ["scalar", "per_row"])
+@pytest.mark.parametrize("H,Hk", [(4, 4), (4, 2), (8, 1)])
+def test_grouped_decode_attention_matches_expanded_formula(H, Hk, pos_kind,
+                                                           jit):
+    """Grouped-query decode over bfloat16 caches computes what the
+    upcast-and-repeat formula computed: the bf16 products are exact in
+    float32, so only the summation order differs."""
+    from repro.models.attention import decode_attention
+
+    B, Smax, dh = 3, 40, 16
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(10 * H + Hk), 3)
+    q = jax.random.normal(kq, (B, 1, H, dh)).astype(jnp.bfloat16)
+    k_cache = jax.random.normal(kk, (B, Smax, Hk, dh)).astype(jnp.bfloat16)
+    v_cache = jax.random.normal(kv, (B, Smax, Hk, dh)).astype(jnp.bfloat16)
+    pos = (jnp.int32(17) if pos_kind == "scalar"
+           else jnp.array([0, 17, Smax - 1], jnp.int32))
+    scale = dh ** -0.5
+
+    def attend(q_):
+        return decode_attention(q_, k_cache, v_cache, pos, scale=scale)
+
+    fn = jax.jit(attend) if jit else attend
+    ref = np.asarray(_expanded_decode_attention(q, k_cache, v_cache, pos,
+                                                scale))
+    # a float32 q holding the bf16 values: the result before the final cast
+    out = fn(q.astype(jnp.float32))
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-6)
+    # bf16 in, bf16 out: the same values rounded once
+    out_bf16 = fn(q)
+    assert out_bf16.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(out_bf16, np.float32),
+        np.asarray(jnp.asarray(ref).astype(jnp.bfloat16), np.float32),
+        rtol=2 ** -7, atol=1e-6)
+
+
+def _jaxpr_values(jaxpr):
+    """Every value defined in a jaxpr and in the jaxprs its equations
+    hold (jit, scan, cond bodies), their inputs included."""
+    from jax.extend import core as jcore
+
+    for eqn in jaxpr.eqns:
+        yield from eqn.outvars
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else (param,)):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    yield from sub.invars
+                    yield from _jaxpr_values(sub)
+
+
+def test_decode_step_holds_no_float32_or_head_repeated_cache():
+    """The jitted decode step of a GQA model reads its bf16 KV cache as
+    it lies: no value has the head-repeated shape (B, Smax, H, dh), and
+    none is a float32 (B, Smax, Hk, dh) copy of a cache."""
+    from repro.models import transformer
+
+    cfg = get_config("internlm2-1.8b", smoke=True)
+    H, Hk, dh = cfg.num_heads, cfg.num_kv_heads, cfg.dh
+    assert H > Hk and jnp.dtype(cfg.dtype) == jnp.bfloat16
+    B, Smax = 2, 32
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    cache = transformer.init_cache(cfg, B, Smax)
+    step = jax.jit(lambda p, c, t, pos: transformer.decode_step(
+        cfg, p, c, t, pos))
+    closed = jax.make_jaxpr(step)(params, cache, jnp.zeros((B, 1), jnp.int32),
+                                  jnp.int32(4))
+    avals = [v.aval for v in _jaxpr_values(closed.jaxpr)]
+    cache_shaped = [a for a in avals if a.shape == (B, Smax, Hk, dh)]
+    assert cache_shaped, "the walk must reach the per-layer cache"
+    assert [a for a in avals if a.shape == (B, Smax, H, dh)] == []
+    assert [a for a in cache_shaped if a.dtype == jnp.float32] == []
+
+
 def test_rejects_non_attention_archs(model):
     cfg = get_config("rwkv6-7b", smoke=True).replace(dtype="float32")
     with pytest.raises(ValueError):
